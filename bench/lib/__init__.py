@@ -1,0 +1,3 @@
+"""The benchmark's yardstick: traffic, trace reduction, arithmetic,
+reference and comparison. Imports nothing of the program except where a
+module says so (the cell drivers under ``bench/kinds``)."""
