@@ -284,9 +284,9 @@ def train_phase(log, cfg: ArchConfig, n_layers: int = TRAIN_LAYERS, seed: int = 
     res = job.run(batch_size=TRAIN_BATCH, max_steps=TRAIN_STEPS, streaming=True)
     wall = time.perf_counter() - t0
     loss = res.metrics["loss"]
-    rate = log.metrics.gauge("train_records_per_s", deployment=dep.deployment_id).value
+    steps = log.metrics.recent_spans("train_step")
     say("train", steps=res.steps, loss=loss, wall_s=wall,
-        last_step_s=TRAIN_BATCH / rate if rate else None,
+        last_step_s=steps[-1]["total_s"] if steps else None,
         **memory(jax.devices()[0]))
     if res.steps != TRAIN_STEPS or not np.isfinite(loss):
         raise AssertionError(f"{res.steps} steps, loss {loss}")

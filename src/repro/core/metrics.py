@@ -12,6 +12,9 @@ the produce/fetch hot path.  Cost model:
   size, metadata apply lag, consumer lag) are registered as *callbacks*
   via :meth:`MetricsRegistry.gauge_fn` and evaluated only at snapshot /
   render time — they never touch the hot path.
+* Spans log each closed stretch of work (name, ``perf_counter_ns``
+  bounds, parent, request or step id) and, while a JAX profiler session
+  records, open a ``repro.<name>`` trace annotation around it.
 * Histograms use fixed geometric buckets (1µs … ~67s, factor 2), so a
   record is an index computation plus one list increment; p50/p99 are
   estimated from bucket upper bounds at snapshot time.
@@ -25,8 +28,11 @@ humans and CI artifacts.
 
 from __future__ import annotations
 
+import itertools
 import json
 import math
+import sys
+import threading
 import time
 from collections import deque
 from typing import Any, Callable, Iterable
@@ -40,6 +46,8 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "METRICS_TOPIC",
+    "SPAN_LOG_BOUND",
+    "TRACE_PREFIX",
     "default_registry",
     "series_key",
 ]
@@ -215,65 +223,185 @@ class Histogram:
         return out
 
 
+# Closed spans each registry keeps for ``recent_spans()``, the oldest
+# dropped first. A 10 s profiler trace of either instrumented path holds
+# about a thousand: the training feed opens some eight spans a step, an
+# LM serving tick a few per decode step (DESIGN.md §9).
+SPAN_LOG_BOUND = 16384
+
+# A span's profiler annotation is named ``repro.<span name>``.
+TRACE_PREFIX = "repro."
+
+_SPAN_SEQ = itertools.count(1)  # process-wide span numbers: parent links
+_OPEN = threading.local()  # per thread: the numbers of open ``with`` spans
+
+
+def _innermost_open() -> int | None:
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1] if stack else None
+
+
+class _Profiler:
+    """Whether a JAX profiler session records, and which one.
+
+    Asked through ``jax.profiler.TraceAnnotation.is_enabled()``, and only
+    once the process has imported jax: without jax no profiler runs, so
+    ``core`` never imports it. Sessions are numbered as spans see them:
+    the number advances each time recording is seen on after it was seen
+    off.
+    """
+
+    def __init__(self):
+        self._lock = make_lock("metrics", name="metrics:profiler")
+        self._annotation: Any = None
+        self._on = False
+        self._session = 0
+
+    def annotation(self) -> Any:
+        """``jax.profiler.TraceAnnotation``, or None before jax is loaded."""
+        if self._annotation is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            self._annotation = getattr(profiler, "TraceAnnotation", None)
+        return self._annotation
+
+    def session(self) -> int:
+        """The recording session's number, or 0 when none records."""
+        ann = self.annotation()
+        on = ann is not None and ann.is_enabled()
+        if on != self._on:
+            with self._lock:
+                if on and not self._on:
+                    self._session += 1
+                self._on = on
+        return self._session if on else 0
+
+
+_PROFILER = _Profiler()
+
+
+def _span_record(
+    name: str,
+    labels: dict[str, Any],
+    seq: int,
+    parent: int | None,
+    span_id: Any,
+    start_ns: int,
+    end_ns: int,
+    session: int,
+    outcome: str = "ok",
+    phases: Iterable[tuple[str, float]] = (),
+) -> dict[str, Any]:
+    return {
+        "span": name,
+        "labels": labels,
+        "outcome": outcome,
+        "total_s": (end_ns - start_ns) / 1e9,
+        "phases": [{"phase": p, "seconds": s} for p, s in phases],
+        "seq": seq,
+        "parent": parent,
+        "id": span_id,
+        "start_ns": start_ns,
+        "end_ns": end_ns,
+        "traced": session > 0,
+        "session": session or None,
+    }
+
+
 class Span:
-    """Lightweight trace span with named phases.
+    """A timed stretch of work with named phases.
 
     ``phase(name)`` closes the running segment and records it into the
     ``<span>_<phase>_seconds`` histogram; ``end()`` records the total
-    into ``<span>_seconds`` and remembers the span in the registry's
-    bounded recent-span buffer for inspection/tests.
+    into ``<span>_seconds`` and appends the span to the registry's span
+    log (``recent_spans``). The histograms carry the span's labels only
+    when the span is ``keyed``; its ``id`` never keys a series.
+
+    Used as a ``with`` block, the span is the parent of every span opened
+    inside it on the same thread, whatever their registry, and while a
+    JAX profiler session records it opens a ``TraceAnnotation`` named
+    ``repro.<span>`` (metadata: its ``id`` and scalar labels), so the
+    span lands in the trace on the profiler's clock, nested as the code
+    nests. A span ended by a later call, possibly on another thread,
+    stays out of the trace: an annotation closes on its own thread,
+    innermost first.
     """
 
-    __slots__ = ("name", "labels", "_registry", "_t0", "_last", "phases", "_done")
+    __slots__ = ("name", "labels", "id", "seq", "parent", "session", "phases",
+                 "_registry", "_keyed", "_t0", "_last", "_done", "_annotation")
 
     def __init__(
         self,
         registry: "MetricsRegistry",
         name: str,
         labels: dict[str, Any] | None = None,
+        *,
+        id: Any = None,
+        parent: "Span | int | None" = None,
+        keyed: bool = False,
     ):
         self.name = name
         self.labels = dict(labels or {})
-        self._registry = registry
-        self._t0 = time.perf_counter()
-        self._last = self._t0
+        self.id = id
+        self.seq = next(_SPAN_SEQ)
+        self.parent = _innermost_open() if parent is None else _seq_of(parent)
+        self.session = _PROFILER.session()
         self.phases: list[tuple[str, float]] = []
+        self._registry = registry
+        self._keyed = keyed
+        self._annotation = None
         self._done = False
+        self._t0 = self._last = time.perf_counter_ns()
+
+    def _histogram(self, name: str) -> Histogram:
+        return self._registry.histogram(name, **(self.labels if self._keyed else {}))
 
     def phase(self, phase_name: str) -> float:
-        now = time.perf_counter()
-        dur = now - self._last
+        now = time.perf_counter_ns()
+        dur = (now - self._last) / 1e9
         self._last = now
         self.phases.append((phase_name, dur))
-        self._registry.histogram(
-            f"{self.name}_{phase_name}_seconds"
-        ).record(dur)
+        self._histogram(f"{self.name}_{phase_name}_seconds").record(dur)
         return dur
 
     def end(self, outcome: str = "ok") -> float:
         if self._done:
             return 0.0
         self._done = True
-        total = time.perf_counter() - self._t0
-        self._registry.histogram(f"{self.name}_seconds").record(total)
-        self._registry._remember_span(
-            {
-                "span": self.name,
-                "labels": self.labels,
-                "outcome": outcome,
-                "total_s": total,
-                "phases": [
-                    {"phase": p, "seconds": s} for p, s in self.phases
-                ],
-            }
-        )
+        t1 = time.perf_counter_ns()
+        total = (t1 - self._t0) / 1e9
+        self._histogram(f"{self.name}_seconds").record(total)
+        self._registry._remember_span(_span_record(
+            self.name, self.labels, self.seq, self.parent, self.id, self._t0, t1,
+            self.session, outcome, self.phases,
+        ))
         return total
 
     def __enter__(self) -> "Span":
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        stack.append(self.seq)
+        if self.session:
+            meta = {k: v for k, v in self.labels.items()
+                    if isinstance(v, (int, float, str))}
+            if self.id is not None:
+                meta["id"] = self.id
+            self._annotation = _PROFILER.annotation()(TRACE_PREFIX + self.name, **meta)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.end("error" if exc_type is not None else "ok")
+        try:
+            self.end("error" if exc_type is not None else "ok")
+        finally:
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+                self._annotation = None
+            _OPEN.stack.pop()
+
+
+def _seq_of(parent: "Span | int") -> int | None:
+    return parent if isinstance(parent, int) else parent.seq
 
 
 class _NullSpan:
@@ -281,6 +409,7 @@ class _NullSpan:
 
     __slots__ = ()
     phases: list = []
+    seq = None
 
     def phase(self, phase_name: str) -> float:
         return 0.0
@@ -296,21 +425,6 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
-
-
-class _Timer:
-    __slots__ = ("_hist", "_t0")
-
-    def __init__(self, hist: Histogram | None):
-        self._hist = hist
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        if self._hist is not None:
-            self._hist.record(time.perf_counter() - self._t0)
 
 
 class MetricsRegistry:
@@ -330,7 +444,7 @@ class MetricsRegistry:
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
         self._gauge_fns: dict[str, Callable[[], float]] = {}
-        self._spans: deque[dict[str, Any]] = deque(maxlen=256)
+        self._spans: deque[dict[str, Any]] = deque(maxlen=SPAN_LOG_BOUND)
         # shared no-op instances for the disabled fast path
         self._null_counter = Counter("__disabled__")
         self._null_gauge = Gauge("__disabled__")
@@ -338,7 +452,7 @@ class MetricsRegistry:
 
     # -- accessors ---------------------------------------------------
 
-    def counter(self, name: str, **labels: Any) -> Counter:
+    def counter(self, name: str, /, **labels: Any) -> Counter:
         if not self.enabled:
             return self._null_counter
         key = series_key(name, labels)
@@ -348,7 +462,7 @@ class MetricsRegistry:
                 c = self._counters.setdefault(key, Counter(key))
         return c
 
-    def gauge(self, name: str, **labels: Any) -> Gauge:
+    def gauge(self, name: str, /, **labels: Any) -> Gauge:
         if not self.enabled:
             return self._null_gauge
         key = series_key(name, labels)
@@ -359,7 +473,7 @@ class MetricsRegistry:
         return g
 
     def histogram(
-        self, name: str, *, sample: int = 1, **labels: Any
+        self, name: str, /, *, sample: int = 1, **labels: Any
     ) -> Histogram:
         """``sample`` (power of two, set by the first creator of a
         series) enables 1-in-``sample`` hot-path sampling after a
@@ -382,21 +496,53 @@ class MetricsRegistry:
         with self._lock:
             self._gauge_fns[series_key(name, labels)] = fn
 
-    def timer(self, name: str, **labels: Any) -> _Timer:
-        if not self.enabled:
-            return _Timer(None)
-        return _Timer(self.histogram(name, **labels))
-
-    def span(self, name: str, **labels: Any) -> Span | _NullSpan:
+    def span(
+        self,
+        name: str,
+        /,
+        *,
+        id: Any = None,
+        parent: Span | int | None = None,
+        keyed: bool = False,
+        **labels: Any,
+    ) -> Span | _NullSpan:
+        """A :class:`Span`. ``id`` (a request id, a step number) is shared
+        by the spans of one request or step; ``parent`` defaults to the
+        innermost ``with`` span open on this thread; ``keyed`` keys the
+        span's histograms by its labels (bounded label sets only)."""
         if not self.enabled:
             return _NULL_SPAN
-        return Span(self, name, labels)
+        return Span(self, name, labels, id=id, parent=parent, keyed=keyed)
+
+    def record_span(
+        self,
+        name: str,
+        start_ns: int,
+        end_ns: int,
+        /,
+        *,
+        id: Any = None,
+        parent: Span | int | None = None,
+        **labels: Any,
+    ) -> None:
+        """Log an interval known only once it is over (a request's wait
+        from submit to admission), on the ``time.perf_counter_ns()``
+        clock: into ``<name>_seconds`` and the span log, never into a
+        profiler trace. It has a parent only when one is given."""
+        if not self.enabled:
+            return
+        self.histogram(f"{name}_seconds").record((end_ns - start_ns) / 1e9)
+        self._remember_span(_span_record(
+            name, labels, next(_SPAN_SEQ), None if parent is None else _seq_of(parent),
+            id, start_ns, end_ns, _PROFILER.session(),
+        ))
 
     def _remember_span(self, record: dict[str, Any]) -> None:
         with self._lock:
             self._spans.append(record)
 
     def recent_spans(self, name: str | None = None) -> list[dict[str, Any]]:
+        """The span log, oldest first, or its spans named ``name``."""
         with self._lock:
             spans = list(self._spans)
         if name is None:
@@ -405,11 +551,11 @@ class MetricsRegistry:
 
     # -- introspection helpers --------------------------------------
 
-    def counter_value(self, name: str, **labels: Any) -> int:
+    def counter_value(self, name: str, /, **labels: Any) -> int:
         c = self._counters.get(series_key(name, labels))
         return c.value if c is not None else 0
 
-    def gauge_value(self, name: str, **labels: Any) -> float:
+    def gauge_value(self, name: str, /, **labels: Any) -> float:
         key = series_key(name, labels)
         g = self._gauges.get(key)
         if g is not None:

@@ -144,10 +144,15 @@ class PrefetchIterator:
         self._errbox: list[BaseException] = []
         self._finished = False
         self._closed = False
+        self._name = name
+        self._metrics = default_registry()
+        # next() calls that found nothing ready: the consumer outran the
+        # worker (its wait is the feed_wait span)
+        self._empty = self._metrics.counter("prefetch_empty_total", name=name)
         # source failures re-raise at the consumer, but are also counted
         # (daemon_errors{daemon=...}) so chaos runs can assert zero
         # unexpected background errors without re-driving every stream
-        errors = default_registry().counter("daemon_errors_total", daemon=name)
+        errors = self._metrics.counter("daemon_errors_total", daemon=name)
         self._thread = threading.Thread(
             target=self._pump,
             args=(iter(it), self._queue, self._stop, self._errbox, self._DONE,
@@ -188,6 +193,12 @@ class PrefetchIterator:
         return self
 
     def __next__(self) -> Any:
+        if not self._finished and self._queue.empty():
+            self._empty.inc()
+        with self._metrics.span("feed_wait", keyed=True, name=self._name):
+            return self._take()
+
+    def _take(self) -> Any:
         # terminal states (source exhausted, error already delivered, or
         # close()d) keep raising StopIteration instead of blocking on a
         # queue no live worker will ever feed again
@@ -726,11 +737,19 @@ class StreamingBatchIterator:
     ) -> Iterator[dict[str, np.ndarray]]:
         """Poll + decode records ``[skip_records, skip_records + count)``
         of this split's window, one bounded fetch at a time."""
+        m = default_registry()
         for r in _window_ranges(self._ranges, skip_records, count):
-            for batch in self.log.iter_range(
+            fetched = iter(self.log.iter_range(
                 r.topic, r.partition, r.offset, r.length, chunk=self.fetch_records
-            ):
-                yield self.codec.decode_frames(batch)
+            ))
+            while True:
+                with m.span("feed_fetch"):
+                    batch = next(fetched, None)
+                if batch is None:
+                    break
+                with m.span("feed_decode"):
+                    chunk = self.codec.decode_frames(batch)
+                yield chunk
 
     def _epoch(self, start_batch: int) -> Iterator[dict[str, np.ndarray]]:
         bs = self.batch_size
@@ -739,6 +758,8 @@ class StreamingBatchIterator:
         parts: list[dict[str, np.ndarray]] = []  # decoded, not-yet-emitted
         head = 0  # rows of parts[0] already emitted
         avail = 0  # unemitted rows buffered across parts
+        m = default_registry()
+        assembled = m.counter("feed_batches_total")
         for chunk in self._chunks(skip, usable - skip):
             rows = next(iter(chunk.values())).shape[0]
             if rows == 0:
@@ -746,35 +767,37 @@ class StreamingBatchIterator:
             parts.append(chunk)
             avail += rows
             while avail >= bs:
-                first = parts[0]
-                first_rows = next(iter(first.values())).shape[0]
-                if first_rows - head >= bs:
-                    # common case: the batch is a pure view into one chunk
-                    batch = {k: v[head : head + bs] for k, v in first.items()}
-                    head += bs
-                else:
-                    # chunk-boundary batch: one batch_size-row concat
-                    need, pieces = bs, []
-                    while need:
-                        cur = parts[0]
-                        cur_rows = next(iter(cur.values())).shape[0]
-                        take = min(need, cur_rows - head)
-                        pieces.append(
-                            {k: v[head : head + take] for k, v in cur.items()}
-                        )
-                        head += take
-                        need -= take
-                        if head == cur_rows:
-                            parts.pop(0)
-                            head = 0
-                    batch = {
-                        k: np.concatenate([p[k] for p in pieces], axis=0)
-                        for k in pieces[0]
-                    }
-                if parts and head == next(iter(parts[0].values())).shape[0]:
-                    parts.pop(0)
-                    head = 0
+                with m.span("feed_assemble"):
+                    first = parts[0]
+                    first_rows = next(iter(first.values())).shape[0]
+                    if first_rows - head >= bs:
+                        # common case: the batch is a pure view into one chunk
+                        batch = {k: v[head : head + bs] for k, v in first.items()}
+                        head += bs
+                    else:
+                        # chunk-boundary batch: one batch_size-row concat
+                        need, pieces = bs, []
+                        while need:
+                            cur = parts[0]
+                            cur_rows = next(iter(cur.values())).shape[0]
+                            take = min(need, cur_rows - head)
+                            pieces.append(
+                                {k: v[head : head + take] for k, v in cur.items()}
+                            )
+                            head += take
+                            need -= take
+                            if head == cur_rows:
+                                parts.pop(0)
+                                head = 0
+                        batch = {
+                            k: np.concatenate([p[k] for p in pieces], axis=0)
+                            for k in pieces[0]
+                        }
+                    if parts and head == next(iter(parts[0].values())).shape[0]:
+                        parts.pop(0)
+                        head = 0
                 avail -= bs
+                assembled.inc()
                 yield batch
 
     def _batches(self) -> Iterator[dict[str, np.ndarray]]:
@@ -983,9 +1006,12 @@ def device_feed(
     mid-epoch.
     """
 
+    m = default_registry()
+
     def place(b: Mapping[str, np.ndarray]) -> dict[str, jax.Array]:
-        if sharding is None:
-            return {k: jax.device_put(v) for k, v in b.items()}
-        return {k: jax.device_put(v, sharding) for k, v in b.items()}
+        with m.span("feed_put"):
+            if sharding is None:
+                return {k: jax.device_put(v) for k, v in b.items()}
+            return {k: jax.device_put(v, sharding) for k, v in b.items()}
 
     return prefetch_iter((place(b) for b in it), depth, name="device_feed")

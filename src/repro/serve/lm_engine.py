@@ -44,6 +44,7 @@ import numpy as np
 from repro.analysis.witness import make_lock
 from repro.core.consumer import ConsumerGroup, RebalanceError
 from repro.core.log import StreamLog
+from repro.core.metrics import default_registry
 from repro.models.model import StreamModel
 from repro.serve.engine import TxnOutputPublisher
 
@@ -68,6 +69,8 @@ class Request:
     prompt: np.ndarray  # (prompt_len,) int32
     max_new: int
     tenant: int = 0  # partitioning key on the request/response topics
+    # perf_counter_ns at the engine's submit(): the engine_queue span's start
+    submitted_ns: int = dataclasses.field(default=0, compare=False)
 
 
 # ------------------------------------------------------- topic record codec
@@ -289,6 +292,8 @@ class ContinuousLMEngine:
         self.useful_steps = 0
         self.admissions = 0
         self.first_token_s: dict[int, float] = {}  # req_id -> TTFT timestamp
+        # engine_queue/admit/decode/finish spans (DESIGN.md §9)
+        self.metrics = default_registry()
 
     def _blocks_needed(self, req: Request) -> int:
         # final decode step writes K/V at position plen + max_new - 2;
@@ -301,6 +306,7 @@ class ContinuousLMEngine:
                 f"request {req.req_id}: {len(req.prompt)}+{req.max_new} tokens "
                 f"exceeds max_blocks={self.max_blocks} * block={self.block_size}"
             )
+        req.submitted_ns = time.perf_counter_ns()
         with self._lock:
             self.queue.append(req)
 
@@ -314,14 +320,15 @@ class ContinuousLMEngine:
 
     def _finish(self, row: int, out: list[tuple[int, np.ndarray]]) -> None:
         slot = self.slots[row]
-        gen = np.asarray(slot.generated[: slot.req.max_new], np.int32)
-        out.append((slot.req.req_id, gen))
-        self.blocks.release(slot.blocks)
-        # zero the row's position + block table so its idle writes land
-        # in the scratch block — a stale table would corrupt whichever
-        # row the freed blocks go to next
-        self.caches = self._clear(self.caches, np.int32(row))
-        self.slots[row] = None
+        with self.metrics.span("engine_finish", id=slot.req.req_id):
+            gen = np.asarray(slot.generated[: slot.req.max_new], np.int32)
+            out.append((slot.req.req_id, gen))
+            self.blocks.release(slot.blocks)
+            # zero the row's position + block table so its idle writes land
+            # in the scratch block — a stale table would corrupt whichever
+            # row the freed blocks go to next
+            self.caches = self._clear(self.caches, np.int32(row))
+            self.slots[row] = None
 
     def _admit_pending(self, out: list[tuple[int, np.ndarray]]) -> None:
         for row in range(self.n_slots):
@@ -337,22 +344,25 @@ class ContinuousLMEngine:
                 with self._lock:
                     self.queue.appendleft(req)  # pool exhausted: retry later
                 return
+            self.metrics.record_span("engine_queue", req.submitted_ns,
+                                     time.perf_counter_ns(), id=req.req_id)
             plen = len(req.prompt)
             nb_prefill = -(-plen // self.block_size)
             bt_row = np.zeros(self.max_blocks, np.int32)
             bt_row[:need] = blocks
-            # host arrays go straight to the params' device
-            logits, self.caches = self._admit(
-                self.params,
-                self.caches,
-                np.asarray(req.prompt, np.int32)[None],
-                np.int32(row),
-                np.asarray(blocks[:nb_prefill], np.int32),
-                bt_row,
-                np.int32(plen),
-            )
-            tok0 = int(jnp.argmax(logits))
-            self.first_token_s[req.req_id] = time.perf_counter()
+            with self.metrics.span("engine_admit", id=req.req_id, tokens=plen):
+                # host arrays go straight to the params' device
+                logits, self.caches = self._admit(
+                    self.params,
+                    self.caches,
+                    np.asarray(req.prompt, np.int32)[None],
+                    np.int32(row),
+                    np.asarray(blocks[:nb_prefill], np.int32),
+                    bt_row,
+                    np.int32(plen),
+                )
+                tok0 = int(jnp.argmax(logits))
+                self.first_token_s[req.req_id] = time.perf_counter()
             self.admissions += 1
             self.slots[row] = _Slot(req, blocks, plen, [tok0])
             self._tok[row, 0] = tok0
@@ -371,9 +381,10 @@ class ContinuousLMEngine:
         pos_vec = np.zeros(self.n_slots, np.int32)
         for r in rows:
             pos_vec[r] = self.slots[r].pos
-        lg, self.caches = self._decode(self.params, self.caches, self._tok, pos_vec)
-        tok = jnp.argmax(lg[:, 0], -1)
-        t = np.asarray(tok)
+        # the host's side of the fused step, the token read-back included
+        with self.metrics.span("engine_decode", rows=len(rows)):
+            lg, self.caches = self._decode(self.params, self.caches, self._tok, pos_vec)
+            t = np.asarray(jnp.argmax(lg[:, 0], -1))
         self.lane_steps += self.n_slots
         self.useful_steps += len(rows)
         for r in rows:
@@ -447,6 +458,9 @@ class LMServingWorker:
         self._tenants: dict[int, int] = {}  # req_id -> tenant for keyed publish
         self.served = 0
         self.alive = True
+        # serve_tick and its children, in the cluster's registry when
+        # there is one (DESIGN.md §9)
+        self.metrics = getattr(log, "metrics", None) or default_registry()
 
     def poll_serve(self, max_records: int = 64) -> int:
         """One tick: poll -> submit -> drain -> publish+commit. Returns
@@ -458,23 +472,37 @@ class LMServingWorker:
             # and resume from committed offsets next tick
             self.consumer.rejoin()
             return 0
-        if self.engine.qsize() >= self.max_queue:
-            self.consumer.pause()
-        else:
-            self.consumer.resume()
-        try:
-            polled = self.consumer.poll(max_records)
-        except RebalanceError:
-            self.consumer.rejoin()
-            return 0
-        for batch in polled:
-            for buf in batch.values:
-                req = decode_request(buf)
-                self._tenants[req.req_id] = req.tenant
-                self.engine.submit(req)
-        completions = self.engine.run_until_drained()
+        with self.metrics.span("serve_tick", worker=self.worker_id):
+            return self._tick(max_records)
+
+    def _tick(self, max_records: int) -> int:
+        m = self.metrics
+        with m.span("serve_poll"):
+            if self.engine.qsize() >= self.max_queue:
+                self.consumer.pause()
+            else:
+                self.consumer.resume()
+            try:
+                polled = self.consumer.poll(max_records)
+            except RebalanceError:
+                self.consumer.rejoin()
+                return 0
+        with m.span("serve_submit"):
+            for batch in polled:
+                for buf in batch.values:
+                    req = decode_request(buf)
+                    self._tenants[req.req_id] = req.tenant
+                    self.engine.submit(req)
+        with m.span("serve_drain"):
+            completions = self.engine.run_until_drained()
         if not polled and not completions:
             return 0
+        # the end of this span is each completion's stamp: published,
+        # and with a publisher committed with the request offsets
+        with m.span("serve_publish", ids=[rid for rid, _ in completions]):
+            return self._publish(completions)
+
+    def _publish(self, completions: list[tuple[int, np.ndarray]]) -> int:
         recs, keys = [], []
         for rid, gen in completions:
             tenant = self._tenants.pop(rid, 0)

@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.control import ControlMessage, poll_control
 from repro.core.controller import ClusterError
 from repro.core.log import StreamBackend
+from repro.core.metrics import MetricsRegistry
 from repro.core.registry import Registry
 from repro.data.pipeline import (
     BatchIterator,
@@ -344,9 +345,11 @@ class TrainingJob:
         total = max_steps if max_steps is not None else epochs * steps_per_epoch
 
         metrics = {}
-        # training throughput metrics (no-op on backends with no registry)
-        reg = getattr(self.log, "metrics", None)
-        instrument = reg is not None and reg.enabled
+        # step spans and throughput (nothing on backends with no registry):
+        # each train_step span holds the feed's feed_wait and the loss
+        # read-back, train_sync, and records train_step_seconds{deployment}
+        reg = getattr(self.log, "metrics", None) or MetricsRegistry(enabled=False)
+        records = reg.counter("train_records_total", deployment=self.deployment_id)
         # batch assembly overlaps the device step (prefetch is a bounded
         # background queue over the same deterministic batch sequence);
         # streaming additionally overlaps the device_put dispatch
@@ -360,27 +363,18 @@ class TrainingJob:
                 for _ in range(start_step):
                     next(stream)
             for step_i in range(start_step, total):
-                t0 = time.perf_counter() if instrument else 0.0
-                nxt = next(stream)
-                batch = (
-                    nxt if streaming
-                    else {k: jnp.asarray(v) for k, v in nxt.items()}
-                )
-                state, m = step_fn(state, batch)
-                metrics = {k: float(v) for k, v in m.items()}
-                if instrument:
-                    dt = time.perf_counter() - t0
-                    reg.histogram(
-                        "train_step_seconds", deployment=self.deployment_id
-                    ).record(dt)
-                    reg.counter(
-                        "train_records_total", deployment=self.deployment_id
-                    ).inc(batch_size)
-                    if dt > 0:
-                        reg.gauge(
-                            "train_records_per_s",
-                            deployment=self.deployment_id,
-                        ).set(batch_size / dt)
+                with reg.span("train_step", id=step_i, keyed=True,
+                              deployment=self.deployment_id):
+                    nxt = next(stream)
+                    batch = (
+                        nxt if streaming
+                        else {k: jnp.asarray(v) for k, v in nxt.items()}
+                    )
+                    state, m = step_fn(state, batch)
+                    with reg.span("train_sync", keyed=True,
+                                  deployment=self.deployment_id):
+                        metrics = {k: float(v) for k, v in m.items()}
+                records.inc(batch_size)
                 done = step_i + 1
                 if self.manager is not None and done % self.ckpt_every == 0:
                     self.manager.save_async(

@@ -293,3 +293,51 @@ def test_serving_group_roundtrip_bare_log(lm):
     assert sorted(got) == sorted(ref)
     for rid in ref:
         np.testing.assert_array_equal(got[rid], ref[rid])
+
+
+def test_serving_tick_spans_and_request_stamps(lm):
+    """Each publishing tick of a worker is serve_poll -> serve_submit ->
+    serve_drain -> serve_publish; each request's queue span ends before
+    its first token, which comes before the end of the serve_publish
+    that carried it (its completion stamp)."""
+    import time
+
+    from repro.core.metrics import default_registry
+
+    cfg, model, params = lm
+    rng = np.random.default_rng(11)
+    c = core.BrokerCluster(3)
+    c.create_topic("lmreq", core.LogConfig(num_partitions=1))
+    for i in range(5):
+        r = Req(i, rng.integers(0, cfg.vocab, 8).astype(np.int32), 2 + i % 3)
+        c.produce("lmreq", encode_request(r), key=tenant_key(r.tenant))
+    engine = _continuous(model, params, n_slots=2)
+    group = LMServingGroup(c, [engine], input_topic="lmreq", response_topic="lmresp",
+                           transactional=True)
+    t0 = time.perf_counter_ns()
+    assert group.drain() == 5
+
+    spans = c.metrics.recent_spans()
+    publishes = [s for s in spans if s["span"] == "serve_publish"]
+    ticks = {s["parent"] for s in publishes}
+    for tick in (s for s in spans if s["seq"] in ticks):
+        assert tick["span"] == "serve_tick" and tick["labels"] == {"worker": "worker-0"}
+        kids = sorted((s for s in spans if s["parent"] == tick["seq"]),
+                      key=lambda s: s["start_ns"])
+        assert [k["span"] for k in kids] == [
+            "serve_poll", "serve_submit", "serve_drain", "serve_publish"]
+        bounds = [tick["start_ns"]] + [t for k in kids for t in (k["start_ns"], k["end_ns"])]
+        assert bounds == sorted(bounds) and bounds[-1] <= tick["end_ns"]
+    published = {rid: s["end_ns"] for s in publishes for rid in s["labels"]["ids"]}
+
+    mine = [s for s in default_registry().recent_spans() if s["start_ns"] >= t0]
+    queued = {s["id"]: s["end_ns"] for s in mine if s["span"] == "engine_queue"}
+    admits = {s["id"] for s in mine if s["span"] == "engine_admit"}
+    finishes = [s["id"] for s in mine if s["span"] == "engine_finish"]
+    decodes = [s["labels"]["rows"] for s in mine if s["span"] == "engine_decode"]
+    assert sorted(published) == sorted(queued) == sorted(admits) == sorted(finishes)
+    assert sorted(published) == list(range(5))
+    assert decodes and all(1 <= n <= 2 for n in decodes)
+    for rid in range(5):
+        first = engine.first_token_s[rid] * 1e9
+        assert queued[rid] <= first <= published[rid]

@@ -170,20 +170,12 @@ class TestRegistry:
         sp = r.span("op")
         sp.phase("x")
         sp.end()
-        with r.timer("t2"):
-            pass
         snap = r.snapshot()
         assert snap["counters"] == {} and snap["gauges"] == {}
         assert snap["histograms"] == {}
         assert r.recent_spans() == []
         # shared null instruments: no per-call allocation churn
         assert r.counter("a") is r.counter("b")
-
-    def test_timer_records_into_histogram(self):
-        r = MetricsRegistry()
-        with r.timer("op_seconds"):
-            pass
-        assert r.histogram("op_seconds").count == 1
 
 
 # --------------------------------------------------------- log / cluster wiring
