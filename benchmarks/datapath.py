@@ -82,8 +82,9 @@ OVR_REPS = 9
 # single-core reference host
 OVR_STEP_DEPTH = 24
 
-# step section: records reshape to (B, S, 1, D) for flash attention
-STEP_SEQ = 64
+# step section: records reshape to (B, S, 1, D) for flash attention,
+# whose kernel takes lengths in multiples of 128
+STEP_SEQ = 128
 STEP_DIM = 64
 STEP_EPOCHS = 2
 
@@ -282,9 +283,7 @@ def bench_step(log, msg) -> dict:
     @jax.jit
     def step(x):
         qkv = x.reshape(att_b, STEP_SEQ, 1, STEP_DIM)
-        return attention_op(
-            qkv, qkv, qkv, causal=True, block_q=STEP_SEQ, block_k=STEP_SEQ
-        ).sum()
+        return attention_op(qkv, qkv, qkv, causal=True).sum()
 
     step(jnp.zeros((OVR_BATCH, STEP_DIM), jnp.float32)).block_until_ready()
     it = StreamingBatchIterator(

@@ -38,6 +38,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import repro.configs as configs  # noqa: E402
 import repro.core as core  # noqa: E402
 import repro.data as data  # noqa: E402
+from repro.core.metrics import default_registry  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models.model import ArchConfig, StreamModel  # noqa: E402
 from repro.models.policy import Policy  # noqa: E402
@@ -74,9 +75,9 @@ LOGIT_TOL = 0.25
 
 # training: yi-6b widths with the depth cut to what one v5e holds. The
 # donated state (bf16 params, f32 AdamW moments) is 8.70 GB at 2 layers
-# and the step's temporaries 5.70 GB (grads, saved activations, the f32
-# attention scores); at 3 layers the two come to 17.8 GB, over the
-# chip's 15.75 GB (compiled for a described v5e)
+# and the step's temporaries 3.75 GB (grads, saved activations; attention
+# saves only q, k, v, its output and logsumexp), compiled for a described
+# v5e
 TRAIN_LAYERS = 2
 TRAIN_BATCH = 2
 TRAIN_SEQ = 1024
@@ -255,6 +256,13 @@ def correctness_phase(served: Served, seed: int = SEED) -> float:
     return worst
 
 
+def attention_calls() -> dict[str, int]:
+    """Traced attention calls so far, by the path each took on a TPU."""
+    reg = default_registry()
+    return {p: reg.counter_value("attention_calls_total", path=p)
+            for p in ("flash", "chunked")}
+
+
 def train_phase(log, cfg: ArchConfig, n_layers: int = TRAIN_LAYERS, seed: int = SEED) -> dict:
     """A few streaming training steps at ``cfg``'s widths with the depth cut
     to ``n_layers``, fed from a token topic on ``log``. Returns the final
@@ -280,14 +288,16 @@ def train_phase(log, cfg: ArchConfig, n_layers: int = TRAIN_LAYERS, seed: int = 
         loss_fn=lambda p, b: model.loss(p, {"tokens": b["data"]}),
         init_fn=model.init, opt=adamw(1e-4), seed=seed,
     )
+    calls_before = attention_calls()
     t0 = time.perf_counter()
     res = job.run(batch_size=TRAIN_BATCH, max_steps=TRAIN_STEPS, streaming=True)
     wall = time.perf_counter() - t0
     loss = res.metrics["loss"]
     steps = log.metrics.recent_spans("train_step")
+    calls = {f"attention_{p}": n - calls_before[p] for p, n in attention_calls().items()}
     say("train", steps=res.steps, loss=loss, wall_s=wall,
         last_step_s=steps[-1]["total_s"] if steps else None,
-        **memory(jax.devices()[0]))
+        **calls, **memory(jax.devices()[0]))
     if res.steps != TRAIN_STEPS or not np.isfinite(loss):
         raise AssertionError(f"{res.steps} steps, loss {loss}")
     return res.metrics

@@ -1,169 +1,83 @@
-"""Flash attention Pallas TPU kernel.
+"""Flash attention on the TPU, forward and backward: splash attention.
 
-Grid ``(B, H, n_q, n_k)`` with the KV dimension innermost and sequential:
-each (q-block, kv-block) step keeps the classic flash running statistics
-(row max ``m``, denominator ``l``, weighted accumulator ``acc``) in VMEM
-scratch that persists across the sequential kv steps. Causal and
-sliding-window blocks that are fully masked are *skipped* with ``pl.when``
-(no MXU work issued) — the FLOP-halving XLA cannot express (DESIGN.md §4,
-EXPERIMENTS.md §Perf).
+The kernels are the splash attention kernels that ship with JAX
+(``jax.experimental.pallas.ops.tpu.splash_attention``): a forward that
+keeps the online-softmax statistics (row max, denominator, accumulator)
+in VMEM and emits the output plus one logsumexp per query row, and dq /
+dkv backward kernels that recompute each block's probabilities from
+that logsumexp. No score tensor reaches HBM in either direction; the
+residuals of the backward are q, k, v, the output and the logsumexp.
 
-Block shapes are BlockSpec-tiled to VMEM: q/o tiles are
-``(block_q, head_dim)``, kv tiles ``(block_k, head_dim)`` — with the
-defaults (512, 128) the working set is ~
-  q 512x128x2B + k/v 2x512x128x2B + acc 512x128x4B + m/l 2x512x128x4B
-  ≈ 1.2 MB of VMEM, well inside the ~16 MB/core budget, and all matmul
-dims are multiples of the 128x128 MXU tile.
+* Masks are per head (one mask object for every head); blocks that the
+  mask covers entirely are skipped, so causal attention issues about half
+  the MXU work of a masked full product.
+* Grouped-query attention is native: query head ``h`` reads KV head
+  ``h // (H / Kv)`` through the kernel's index map, so K/V are never
+  repeated in HBM.
+* Splash does not scale the logits: q is multiplied by ``1/sqrt(D)``
+  first, and a soft cap, if any, applies to the scaled logits as in
+  ``ref.mha``.
+* Block sizes follow the sequence: the largest of 512, 256 and 128 that
+  divides it, for the forward and both backward kernels.
 
-Supports: causal masking, sliding window, gemma-style logit softcap.
-Validated against ``ref.mha`` in interpret mode (tests/test_kernels.py).
+Validated against ``ref.mha`` (forward and the gradients of q, k and v)
+in interpret mode (tests/test_kernels.py), and compiled for a described
+v5e (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-NEG_INF = -1e30
+BLOCKS = (512, 256, 128)
 
 
-def _attn_kernel(
-    q_ref,  # (block_q, D)
-    k_ref,  # (block_k, D)
-    v_ref,  # (block_k, D)
-    o_ref,  # (block_q, D)
-    m_ref,  # VMEM scratch (block_q, 128) f32
-    l_ref,  # VMEM scratch (block_q, 128) f32
-    acc_ref,  # VMEM scratch (block_q, D) f32
-    *,
-    causal: bool,
-    window: int | None,
-    softcap: float | None,
-    scale: float,
-    block_q: int,
-    block_k: int,
-    n_k: int,
-):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def block_size(seq: int) -> int:
+    """The largest of ``BLOCKS`` dividing ``seq``."""
+    for b in BLOCKS:
+        if seq % b == 0:
+            return b
+    raise ValueError(f"sequence length {seq} is not a multiple of {BLOCKS[-1]}")
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-
-    # static-shape block skip predicate (computed on scalars)
-    needed = jnp.bool_(True)
-    if causal:
-        needed &= k_start <= q_start + block_q - 1  # some key <= some query
+def _mask(seq: int, causal: bool, window: int | None):
+    shape = (seq, seq)
     if window is not None:
-        needed &= k_start + block_k - 1 > q_start - window  # inside window
-
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bk)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        ok = jnp.bool_(True)
-        if causal:
-            ok &= k_pos <= q_pos
-        if window is not None:
-            ok &= k_pos > q_pos - window
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]  # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)  # (bq, bk)
-        corr = jnp.exp(m_prev - m_new)  # (bq, 1)
-        l_new = corr * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(ik == n_k - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_ref[...] / safe).astype(o_ref.dtype)
+        # key j is seen by query i when i - window < j (and j <= i if causal)
+        return splash.LocalMask(shape, (window - 1, 0 if causal else None), offset=0)
+    return splash.CausalMask(shape) if causal else splash.FullMask(shape)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "causal", "window", "softcap", "block_q", "block_k", "interpret",
-    ),
-)
 def flash_attention(
     q: jax.Array,  # (B, H, S, D)
-    k: jax.Array,  # (B, H, S, D)
+    k: jax.Array,  # (B, Kv, S, D), H a multiple of Kv
     v: jax.Array,
     *,
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
-    block_q: int = 512,
-    block_k: int = 512,
     interpret: bool,
 ) -> jax.Array:
-    b, h, s, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
-    n_q, n_k = s // block_q, s // block_k
-    scale = 1.0 / math.sqrt(d)
-
-    kernel = functools.partial(
-        _attn_kernel,
-        causal=causal,
-        window=window,
-        softcap=softcap,
-        scale=scale,
-        block_q=block_q,
-        block_k=block_k,
-        n_k=n_k,
+    """Self-attention of ``q`` over ``k``/``v``; returns (B, H, S, D) in
+    ``q``'s dtype. Differentiable in q, k and v."""
+    _, h, s, d = q.shape
+    b = block_size(s)
+    sizes = splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        block_q_dq=b, block_kv_dq=b,
     )
-    return pl.pallas_call(
-        kernel,
-        grid=(b, h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((None, None, block_k, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((None, None, block_k, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, None, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, d), jnp.float32),
-        ],
+    kernel = splash.make_splash_mha(
+        splash.MultiHeadMask([_mask(s, causal, window)] * h),
+        block_sizes=sizes,
+        attn_logits_soft_cap=softcap,
+        head_shards=1,
+        q_seq_shards=1,
         interpret=interpret,
-        name="flash_attention",
-    )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    )
+    scaled = (q.astype(jnp.float32) / math.sqrt(d)).astype(q.dtype)
+    return jax.vmap(kernel)(scaled, k, v)

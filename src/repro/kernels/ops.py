@@ -1,8 +1,9 @@
 """jit'd wrappers bridging model-layer calling conventions to the kernels.
 
 The model layers use (B, S, H, D) activation layout and grouped (GQA /
-SSD-group) KV; the kernels want (B, H, S, D) with per-head tensors. These
-wrappers do the (XLA-fused) transposes/repeats and are the only entry
+SSD-group) KV; the kernels want (B, H, S, D). These wrappers do the
+(XLA-fused) transposes, and the repeats of grouped tensors for the scans
+(flash attention reads grouped K/V itself), and are the only entry
 points the rest of the codebase calls. :func:`default_interpret` is the
 one place that chooses the Pallas interpreter: on the CPU backend only.
 The kernels themselves take ``interpret`` with no default, so no caller
@@ -28,7 +29,7 @@ def default_interpret() -> bool:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "softcap", "block_q", "block_k")
+    jax.jit, static_argnames=("causal", "window", "softcap", "interpret")
 )
 def attention_op(
     q: jax.Array,  # (B, S, H, D)
@@ -38,28 +39,19 @@ def attention_op(
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    rep = h // kv
-    qt = jnp.moveaxis(q, 1, 2)  # (B, H, S, D)
-    kt = jnp.moveaxis(k, 1, 2)
-    vt = jnp.moveaxis(v, 1, 2)
-    if rep != 1:
-        kt = jnp.repeat(kt, rep, axis=1)
-        vt = jnp.repeat(vt, rep, axis=1)
+    """Flash self-attention in model layout. GQA K/V go to the kernel
+    unrepeated. ``interpret`` None is :func:`default_interpret`; a caller
+    that knows it is lowered for a TPU passes False."""
     out = flash_attention(
-        qt,
-        kt,
-        vt,
+        jnp.moveaxis(q, 1, 2),  # (B, H, S, D)
+        jnp.moveaxis(k, 1, 2),
+        jnp.moveaxis(v, 1, 2),
         causal=causal,
         window=window,
         softcap=softcap,
-        block_q=min(block_q, s),
-        block_k=min(block_k, s),
-        interpret=default_interpret(),
+        interpret=default_interpret() if interpret is None else interpret,
     )
     return jnp.moveaxis(out, 1, 2)
 

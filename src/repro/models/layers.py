@@ -14,7 +14,10 @@ Conventions:
     and the GQA einsum runs grouped — per-device score block is
     (B_loc, K, G, Qb/tp, S);
   - ``none``:  replicated attention compute (no model axis / tiny models).
-* queries are processed in chunks (``lax.map`` over blocks) so fp32 score
+* unsharded causal or full self-attention at 128-multiple lengths, lowered
+  for a TPU, runs the flash kernel (``kernels/flash_attention.py``,
+  forward and backward; :func:`flash_applies`). Elsewhere queries are
+  processed in chunks (``lax.map`` over blocks) so fp32 score
   blocks never exceed (B, H, q_block, S) — the "XLA-flash" pattern. Sliding
   window layers slice K/V to a static (window + q_block) span per block:
   O(S·window) work, not O(S²).
@@ -34,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core.metrics import default_registry
+from repro.kernels.ops import attention_op
 from repro.models.policy import Policy
 
 __all__ = [
@@ -44,6 +49,7 @@ __all__ = [
     "attn_strategy",
     "decode_attention",
     "embed_init",
+    "flash_applies",
     "paged_decode_attention",
     "layer_norm",
     "mlp",
@@ -267,14 +273,15 @@ def attention(
     kv_source: jax.Array | None = None,  # encoder states for cross attention
     return_kv: bool = False,  # prefill: also return unrepeated K/V
 ):
-    """Full-sequence attention (training / prefill), chunked over queries."""
+    """Full-sequence attention (training / prefill): the flash kernel
+    where :func:`flash_applies` and the program is lowered for a TPU,
+    else chunked over queries."""
     b, s, d = x.shape
     if positions is None:
         positions = jnp.arange(s)
     strat = attn_strategy(ap, policy, s)
     batch = policy.batch_spec(b)
     tp = policy.tp_axis
-    scale = 1.0 / math.sqrt(ap.head_dim)
 
     if ap.cross:
         src = kv_source
@@ -286,6 +293,10 @@ def attention(
         q, k, v = _project_qkv(p, x, ap, positions)
         src_pos = positions
     kv_out = (k, v) if return_kv else None
+    flash = flash_applies(ap, policy, s)
+    default_registry().counter(
+        "attention_calls_total", path="flash" if flash else "chunked"
+    ).inc()
 
     if strat == "seq":
         out = _context_parallel_attention(q, k, v, positions, src_pos, ap, policy)
@@ -296,12 +307,28 @@ def attention(
             spec = P(batch, None, tp, None)
         else:
             spec = P(batch, None, None, None)
-        q, k, v = wsc(q, spec), wsc(k, spec if strat == "heads" else spec), wsc(v, spec)
-        out = _chunked_attention(
-            q, k, v, positions, src_pos, ap,
-            block_spec=spec, out_spec=spec, grouped=False,
-            unroll=policy.unroll,
-        )
+        q, k, v = wsc(q, spec), wsc(k, spec), wsc(v, spec)
+
+        def chunked(q, k, v):
+            return _chunked_attention(
+                q, k, v, positions, src_pos, ap,
+                block_spec=spec, out_spec=spec, grouped=False,
+                unroll=policy.unroll,
+            )
+
+        if flash:
+            # the kernel where the program is lowered for a TPU; the
+            # chunked path on every other platform
+            out = jax.lax.platform_dependent(
+                q, k, v,
+                tpu=partial(
+                    attention_op, causal=ap.causal, softcap=ap.softcap,
+                    interpret=False,
+                ),
+                default=chunked,
+            )
+        else:
+            out = chunked(q, k, v)
         out = wsc(out, spec)
 
     y = jnp.einsum("bshd,hdm->bsm", out, p["wo"])
@@ -309,6 +336,23 @@ def attention(
     if return_kv:
         return y, kv_out[0], kv_out[1]
     return y
+
+
+def flash_applies(ap: AttnParams, policy: Policy, seq_len: int) -> bool:
+    """Whether full-sequence attention of this shape takes the flash
+    kernel when lowered for a TPU: causal or full self-attention with no
+    window, sequence and head dim multiples of 128, on operands no mesh
+    shards. Positions are taken to ascend one by one, as every caller
+    passes them."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (
+        not ap.cross
+        and ap.window is None
+        and seq_len % 128 == 0
+        and ap.head_dim % 128 == 0
+        and policy.size(tuple(policy.mesh_axes)) == 1
+        and (mesh.empty or mesh.size == 1)
+    )
 
 
 def _chunked_attention(q, k, v, q_pos, k_pos, ap: AttnParams, *,

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import block_size, flash_attention
 from repro.kernels.ops import attention_op, rglru_op, ssd_op
 from repro.kernels.rglru_scan import rglru_scan_kernel
 from repro.kernels.ssd_scan import ssd_scan
@@ -26,24 +26,60 @@ def test_flash_attention_sweep(b, h, s, d, dtype, causal, window, cap):
     kk = jax.random.normal(ks[1], (b, h, s, d), dtype)
     v = jax.random.normal(ks[2], (b, h, s, d), dtype)
     out = flash_attention(q, kk, v, causal=causal, window=window, softcap=cap,
-                          block_q=128, block_k=128, interpret=True)
+                          interpret=True)
     want = ref.mha(q, kk, v, causal=causal, window=window, softcap=cap)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), atol=TOL[dtype], rtol=TOL[dtype]
     )
 
 
-@pytest.mark.parametrize("blocks", [(64, 64), (128, 64), (64, 128), (256, 256)])
-def test_flash_attention_block_shapes(blocks):
-    bq, bk = blocks
+@pytest.mark.parametrize("s,block", [(128, 128), (384, 128), (768, 256), (1024, 512)])
+def test_flash_attention_block_shapes(s, block):
+    """Blocks follow the sequence: the largest of 512, 256, 128 dividing it."""
+    assert block_size(s) == block
     k = jax.random.PRNGKey(0)
     ks = jax.random.split(k, 3)
-    q = jax.random.normal(ks[0], (1, 2, 256, 64))
-    kk = jax.random.normal(ks[1], (1, 2, 256, 64))
-    v = jax.random.normal(ks[2], (1, 2, 256, 64))
-    out = flash_attention(q, kk, v, block_q=bq, block_k=bk, interpret=True)
+    q = jax.random.normal(ks[0], (1, 2, s, 64))
+    kk = jax.random.normal(ks[1], (1, 2, s, 64))
+    v = jax.random.normal(ks[2], (1, 2, s, 64))
+    out = flash_attention(q, kk, v, interpret=True)
     want = ref.mha(q, kk, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def _scaled_err(got, want) -> float:
+    g = np.asarray(got, np.float32)
+    w = np.asarray(want, np.float32)
+    return float(np.abs(g - w).max() / max(np.abs(w).max(), 1.0))
+
+
+@pytest.mark.parametrize("s", [256, 512])
+@pytest.mark.parametrize("h,kv", [(2, 2), (8, 2), (8, 1)])  # GQA ratios 1, 4, 8
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grads(s, h, kv, causal, dtype):
+    """Forward and the gradients of q, k and v against ``ref.mha`` on
+    K/V repeated to every query head; the kernel reads them grouped."""
+    ks = jax.random.split(jax.random.PRNGKey(s + 10 * h + kv), 4)
+    q = jax.random.normal(ks[0], (1, h, s, 128), dtype)
+    kk = jax.random.normal(ks[1], (1, kv, s, 128), dtype)
+    v = jax.random.normal(ks[2], (1, kv, s, 128), dtype)
+    dout = jax.random.normal(ks[3], (1, h, s, 128), dtype)
+
+    def kernel(q, kk, v):
+        return flash_attention(q, kk, v, causal=causal, interpret=True)
+
+    def oracle(q, kk, v):
+        rep = h // kv
+        return ref.mha(q, jnp.repeat(kk, rep, 1), jnp.repeat(v, rep, 1), causal=causal)
+
+    out, kernel_vjp = jax.vjp(kernel, q, kk, v)
+    want, oracle_vjp = jax.vjp(oracle, q, kk, v)
+    tol = TOL[dtype]
+    assert _scaled_err(out, want) < tol
+    for name, got, exp in zip("qkv", kernel_vjp(dout), oracle_vjp(dout)):
+        assert got.shape == exp.shape and got.dtype == exp.dtype, name
+        assert _scaled_err(got, exp) < tol, (name, _scaled_err(got, exp))
 
 
 @pytest.mark.parametrize("b,h,s,p,n,g,chunk", [
@@ -91,13 +127,13 @@ def test_rglru_scan_sweep(b, s, c, t):
 
 
 def test_attention_op_gqa():
-    """Model-layout wrapper repeats grouped KV correctly."""
+    """Model-layout wrapper hands grouped KV to the kernel unrepeated."""
     k = jax.random.PRNGKey(7)
     ks = jax.random.split(k, 3)
     q = jax.random.normal(ks[0], (2, 128, 8, 32))
     kk = jax.random.normal(ks[1], (2, 128, 2, 32))
     v = jax.random.normal(ks[2], (2, 128, 2, 32))
-    out = attention_op(q, kk, v, block_q=64, block_k=64)
+    out = attention_op(q, kk, v)
     kr = jnp.repeat(jnp.moveaxis(kk, 1, 2), 4, axis=1)
     vr = jnp.repeat(jnp.moveaxis(v, 1, 2), 4, axis=1)
     want = jnp.moveaxis(ref.mha(jnp.moveaxis(q, 1, 2), kr, vr), 1, 2)

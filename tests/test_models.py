@@ -193,3 +193,53 @@ def test_param_counts_match_assigned_scale():
     for aid, (lo, hi) in expect.items():
         n = C.get(aid).param_count()
         assert lo <= n <= hi, f"{aid}: {n:,} params outside [{lo:,.0f}, {hi:,.0f}]"
+
+
+# ------------------------------------------------ flash attention selection
+YI_HEADS = dict(n_heads=8, n_kv=2, head_dim=128)  # yi's head dim, GQA 4
+
+
+@pytest.mark.parametrize("case,ap_kw,s,mesh_axes,want", [
+    ("causal", {}, 256, {}, True),
+    ("full", dict(causal=False), 256, {}, True),
+    ("one_device_mesh", {}, 256, {"data": 1, "model": 1}, True),
+    ("cross", dict(cross=True), 256, {}, False),
+    ("window", dict(window=128), 256, {}, False),
+    ("ragged", {}, 200, {}, False),
+    ("head_dim_64", dict(head_dim=64), 256, {}, False),
+    ("tp_mesh", {}, 256, {"model": 2}, False),
+    ("dp_mesh", {}, 256, {"data": 2, "model": 1}, False),
+])
+def test_flash_attention_selection(case, ap_kw, s, mesh_axes, want):
+    """Causal or full self-attention at 128-multiple lengths on operands
+    no mesh shards takes the kernel; every other shape stays chunked."""
+    from repro.models import layers as L
+
+    ap = L.AttnParams(**{**YI_HEADS, **ap_kw})
+    assert L.flash_applies(ap, Policy(mesh_axes=mesh_axes), s) is want
+
+
+def _attention_calls(path: str) -> int:
+    from repro.core.metrics import default_registry
+
+    return default_registry().counter_value("attention_calls_total", path=path)
+
+
+@pytest.mark.parametrize("s,path", [(256, "flash"), (200, "chunked")])
+def test_attention_calls_counted_and_cpu_lowers_chunked(s, path):
+    """Each traced attention call counts once under the path its shape
+    selects; lowered for the CPU, the train step holds no Pallas kernel."""
+    cfg = dataclasses.replace(C.get_reduced("yi-6b"), head_dim=128)
+    m = StreamModel(cfg, Policy())
+    params = m.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.zeros((2, s), jnp.int32)}
+    other = "chunked" if path == "flash" else "flash"
+    before = _attention_calls(path), _attention_calls(other)
+    step = jax.jit(jax.value_and_grad(lambda p: m.loss(p, batch)[0]))
+    lowered = step.lower(params)
+    assert _attention_calls(path) > before[0]
+    assert _attention_calls(other) == before[1]
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert "tpu_custom_call" not in lowered.compile().as_text()
+    loss, _ = step(params)
+    assert np.isfinite(float(loss))

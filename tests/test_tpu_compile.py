@@ -22,6 +22,8 @@ from repro.kernels.rglru_scan import rglru_scan_kernel
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models.model import StreamModel
 from repro.models.policy import Policy
+from repro.train.optimizer import adamw
+from repro.train.trainer import build_train_step
 
 # the continuous engine's paged cache at the chip run's size
 N_SLOTS, N_BLOCKS, BLOCK, MAX_BLOCKS = 8, 256, 16, 64
@@ -62,9 +64,37 @@ def _kernel_compiled(compiled) -> bool:
 
 
 def test_flash_attention_yi6b_heads(one_chip):
+    """Forward and backward at yi-6b's 32 query / 4 KV heads of 128."""
     q = jax.ShapeDtypeStruct((1, 32, 2048, 128), jnp.bfloat16, sharding=one_chip)
-    c = _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False), q, q, q)
+    kv = jax.ShapeDtypeStruct((1, 4, 2048, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert _kernel_compiled(c)
+
+
+# the yi-6b-l2 train step's temporaries with the chunked XLA attention,
+# whose backward read float32 scores stacked per layer and query chunk
+CHUNKED_STEP_TEMP_BYTES = 5_695_114_752
+
+
+def test_train_step_yi6b_l2_takes_flash(one_chip):
+    """The train step at yi-6b widths, 2 layers, 2 x 1024 lowers its
+    attention to the kernel, and holds no score stacks."""
+    cfg = dataclasses.replace(configs.get("yi-6b"), n_layers=2)
+    model = StreamModel(cfg, Policy())
+    opt = adamw(3e-4)
+    step, _ = build_train_step(model, opt)
+    state = _shapes(one_chip, jax.eval_shape(
+        lambda k: (lambda p: {"params": p, "opt": opt.init(p)})(model.init(k)),
+        jax.random.PRNGKey(0),
+    ))
+    compiled = step.lower(state, {"tokens": _ints(one_chip, 2, 1024)}).compile()
+    assert _kernel_compiled(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < CHUNKED_STEP_TEMP_BYTES
 
 
 @pytest.mark.parametrize("b,c", [(2, 4096), (1, 2560)])  # C=2560: recurrentgemma-9b
