@@ -53,21 +53,20 @@ def training(cell, seed: int, what: str, devices) -> dict:
     import jax.numpy as jnp
 
     from bench.kinds import stream_train as st
-    from bench.kinds.common import Ctx, model
-    from bench.lib.reference import Dims
+    from bench.kinds.common import Ctx
     from repro.train import adamw
     from repro.train.trainer import build_train_step
 
     ctx = Ctx(cell=cell, seed=seed, seconds=0.0, trace=False, devices=devices,
               out_dir=Path("."), started=0.0)
-    dm = Dims.from_config(cell.config)
+    dm = cell.reference.dims(cell.config)
     ref = st.reference_readings(ctx, dm)
     limits = cell.config["limits"]
     if what == "control":
         low = st.reference_readings(ctx, dm, fp8=True)
         checks = st.compare(low["losses"], low["grad_norms"], low["delta_norms"], ref, limits)
     else:
-        m = model(cell.config)
+        m = cell.program.model(cell.config)
         opt = adamw(cell.config["train"]["lr"])
         step_fn, _ = build_train_step(m, opt)
         key = jax.random.PRNGKey(st.traffic.key_seed(seed))
@@ -79,7 +78,7 @@ def training(cell, seed: int, what: str, devices) -> dict:
             b = next(batches)
             return {"tokens": jnp.asarray(b[: len(b) // 2] if half else b)}
 
-        state, losses, grads, deltas = st.checked_steps(step_fn, state, next_batch, m, key)
+        state, losses, grads, deltas = st.checked_steps(ctx, step_fn, state, next_batch, m, key)
         del state
         gc.collect()
         checks = st.compare(losses, grads, deltas, ref, limits)

@@ -1,2 +1,3 @@
-"""Cell drivers, one per traffic ``kind``. Only these modules import the
-program under test."""
+"""Cell drivers, one per traffic ``kind``. These and the ``program``
+module each configuration names are what import the program under
+test."""

@@ -1,5 +1,7 @@
-"""What every cell driver shares: the run's context, the program's model
-built from a configuration file, and the traced window."""
+"""What every cell driver shares: the run's context and the traced
+window. The program's model, the reference and the counts come from the
+two modules that the cell's configuration names (``Cell.program``,
+``Cell.reference``)."""
 
 from __future__ import annotations
 
@@ -12,10 +14,7 @@ from pathlib import Path
 import jax
 
 from bench.lib import trace as trace_lib
-from bench.lib.reference import Dims
 from bench.lib.spec import Cell
-from repro.models.model import ArchConfig, StreamModel
-from repro.models.policy import Policy
 
 
 @dataclasses.dataclass
@@ -31,25 +30,6 @@ class Ctx:
 
     def say(self, *parts) -> None:
         print(f"[{self.cell.name}]", *parts, file=sys.stderr, flush=True)
-
-
-def arch(config: dict) -> ArchConfig:
-    """The program's architecture from a configuration file's published
-    (hf) keys."""
-    dm = Dims.from_config(config)
-    return ArchConfig(
-        name=config["name"], d_model=dm.d_model, n_layers=dm.n_layers,
-        n_heads=dm.n_heads, n_kv_heads=dm.n_kv_heads, head_dim=dm.head_dim,
-        d_ff=dm.d_ff, vocab=dm.vocab, rope_theta=dm.rope_theta, norm_eps=dm.norm_eps,
-    )
-
-
-def model(config: dict, **policy) -> StreamModel:
-    p = config["precision"]
-    return StreamModel(arch(config), Policy(
-        param_dtype=p["param_dtype"], compute_dtype=p["compute_dtype"],
-        kv_cache_dtype=p["kv_cache_dtype"], **policy,
-    ))
 
 
 class TracedWindow:

@@ -37,9 +37,8 @@ import jax
 import numpy as np
 
 import repro.core as core
-from bench.kinds.common import Ctx, TracedWindow, model
-from bench.lib import reference, traffic
-from bench.lib.reference import Dims
+from bench.kinds.common import Ctx, TracedWindow
+from bench.lib import traffic
 from bench.lib.probes import CompileLog, peak_bytes, tree_bytes
 from repro.models.model import quantize_params
 from repro.serve import (
@@ -125,7 +124,7 @@ def _end_offsets(log, topic: str) -> list[int]:
 def _build(ctx: Ctx):
     cfg, mix = ctx.cell.config, ctx.cell.mix
     extra = {"weights_int8": True} if ctx.variant == "program_int8" else {}
-    m = model(cfg, **extra)
+    m = ctx.cell.program.model(cfg, **extra)
     key = jax.random.PRNGKey(traffic.key_seed(ctx.seed))
     engines = []
     for dev in ctx.devices[: ctx.cell.chips]:
@@ -263,7 +262,7 @@ def latencies_ms(w: dict) -> list[float]:
 
 def run(ctx: Ctx, peak: dict) -> dict:
     cfg, mix = ctx.cell.config, ctx.cell.mix
-    dm = Dims.from_config(cfg)
+    dm = ctx.cell.reference.dims(cfg)
     m, engines, log, group = _build(ctx)
     plan = traffic.schedule(mix, ctx.seconds, ctx.seed, dm.vocab)
     n_warm = _warm(ctx, log, group, dm.vocab)
@@ -281,7 +280,8 @@ def run(ctx: Ctx, peak: dict) -> dict:
     if lat:
         e2e["req_latency_p50_ms"] = float(np.percentile(lat, 50))
         e2e["req_latency_p95_ms"] = float(np.percentile(lat, 95))
-    rd = {"dims": dm, "precision": cfg["precision"], "chips": len(engines),
+    rd = {"dims": dm, "counts": ctx.cell.reference, "precision": cfg["precision"],
+          "chips": len(engines),
           "window_s": ctx.seconds, "requests": w["requests"], "window": w["window"]}
     if traced:
         rd["traced"] = w["traced"]
@@ -362,6 +362,7 @@ def _gaps(ctx: Ctx, dm, sample) -> list[np.ndarray]:
     serve = ctx.cell.config["serve"]
     t_max = serve["max_blocks"] * serve["block_size"]
     p_max = ctx.cell.mix["max_new"]["max"]
+    reference = ctx.cell.reference
     key = jax.random.PRNGKey(traffic.key_seed(ctx.seed))
     with jax.default_device(ctx.devices[0]):
         params = jax.jit(lambda k: reference.init_params(dm, k))(key)

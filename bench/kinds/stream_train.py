@@ -9,8 +9,10 @@ step's loss back as ``TrainingJob.run`` does. One state and one step are
 built; set-up drives them from the seed through their first steps, which
 are checked, and the window continues with the same objects.
 
-The run is judged on the first three steps against the float32
-reference started from the same seed on the same rows:
+The model, the reference, the leaves' names and the counts come from
+the modules the configuration names (``Cell.program``,
+``Cell.reference``). The run is judged on the first three steps against
+the float32 reference started from the same seed on the same rows:
 
 - each step's loss;
 - per leaf, the norm of the first gradient as the optimizer received
@@ -36,9 +38,8 @@ import numpy as np
 
 import repro.core as core
 import repro.data as data
-from bench.kinds.common import Ctx, TracedWindow, model
-from bench.lib import flops, reference, traffic
-from bench.lib.reference import Dims
+from bench.kinds.common import Ctx, TracedWindow
+from bench.lib import traffic
 from bench.lib.probes import peak_bytes, tree_bytes
 from repro.data.pipeline import StreamingBatchIterator, device_feed
 from repro.train import adamw
@@ -48,17 +49,10 @@ TOPIC = "lm-tokens"
 CHECKED_STEPS = 3
 
 
-def leaf_name(path) -> str:
-    """The reference's name of one of the program's parameter leaves:
-    its innermost key, or the key above a norm's ``w``."""
-    keys = [str(getattr(k, "key", k)) for k in path]
-    return keys[-2] if keys[-1] == "w" else keys[-1]
-
-
 def backlog_records(ctx: Ctx, dm, seq: int, peak: dict) -> int:
     """Records the chip could train on in the mix's stated seconds at its
     peak rate: the feed never runs dry."""
-    per_seq = flops.train_flops_per_sequence(dm, seq)
+    per_seq = ctx.cell.reference.train_flops_per_sequence(dm, seq)
     return math.ceil(ctx.cell.mix["backlog_seconds_at_peak"] * peak["bf16_flops_per_s"] / per_seq)
 
 
@@ -82,16 +76,16 @@ def _norms_fn():
     return first_grad, change
 
 
-def _by_name(tree) -> dict[str, float]:
+def _by_name(tree, leaf_name) -> dict[str, float]:
     return {leaf_name(p): float(v) for p, v in jax.tree_util.tree_leaves_with_path(tree)}
 
 
 def run(ctx: Ctx, peak: dict) -> dict:
     cfg, mix = ctx.cell.config, ctx.cell.mix
     tcfg = cfg["train"]
-    dm = Dims.from_config(cfg)
+    dm = ctx.cell.reference.dims(cfg)
     batch, seq = tcfg["batch"], tcfg["seq"]
-    m = model(cfg)
+    m = ctx.cell.program.model(cfg)
     opt = adamw(tcfg["lr"])
     log = core.BrokerCluster(3)
     log.create_topic(TOPIC, core.LogConfig(num_partitions=1))
@@ -121,7 +115,7 @@ def run(ctx: Ctx, peak: dict) -> dict:
         return state, loss
 
     try:
-        state, losses, grads, deltas = checked_steps(step_fn, state, next_batch, m, key)
+        state, losses, grads, deltas = checked_steps(ctx, step_fn, state, next_batch, m, key)
         for _ in range(mix["warm_steps"]):
             state, _ = one_step(state)
 
@@ -156,7 +150,7 @@ def run(ctx: Ctx, peak: dict) -> dict:
     tokens = steps * batch * seq
     e2e = {"train_tokens_per_s": tokens / (t1 - t0)}
     ctx.say(f"window_steps={steps} window_s={t1 - t0:.4f} losses={losses} peak_bytes={peak_mem}")
-    rd = {"dims": dm, "precision": cfg["precision"], "chips": 1,
+    rd = {"dims": dm, "counts": ctx.cell.reference, "precision": cfg["precision"], "chips": 1,
           "train": {"steps": steps, "seconds": t1 - t0, "batch": batch, "seq": seq,
                     "traced_steps": traced_steps}}
 
@@ -168,19 +162,21 @@ def run(ctx: Ctx, peak: dict) -> dict:
             "window_open": t0}
 
 
-def checked_steps(step_fn, state, next_batch, m, key):
+def checked_steps(ctx: Ctx, step_fn, state, next_batch, m, key):
     """The first ``CHECKED_STEPS`` steps of ``state``, with the program's
     readings: each step's loss, the per-leaf norm of the first gradient
-    as the optimizer received it, the per-leaf norm of the change."""
+    as the optimizer received it, the per-leaf norm of the change; each
+    leaf under its reference name."""
+    leaf_name = ctx.cell.program.leaf_name
     first_grad, change = _norms_fn()
     losses, grads = [], {}
     for step in range(1, CHECKED_STEPS + 1):
         state, metrics = step_fn(state, next_batch())
         losses.append({k: float(v) for k, v in metrics.items()}["loss"])
         if step == 1:
-            grads = _by_name(first_grad(state["opt"]["m"], 0.9))
+            grads = _by_name(first_grad(state["opt"]["m"], 0.9), leaf_name)
     start = jax.jit(m.init)(key)
-    deltas = _by_name(change(state["params"], start))
+    deltas = _by_name(change(state["params"], start), leaf_name)
     return state, losses, grads, deltas
 
 
@@ -188,13 +184,13 @@ def checked_rows(ctx: Ctx) -> list[np.ndarray]:
     """The rows of the checked steps, one batch a step, as the stream
     delivers them."""
     t = ctx.cell.config["train"]
-    vocab = Dims.from_config(ctx.cell.config).vocab
+    vocab = ctx.cell.reference.dims(ctx.cell.config).vocab
     r = rows(ctx, t["batch"] * CHECKED_STEPS, t["seq"], vocab)
     return [r[i * t["batch"]:(i + 1) * t["batch"]] for i in range(CHECKED_STEPS)]
 
 
 def reference_readings(ctx: Ctx, dm, fp8: bool = False) -> dict:
-    cfg = ctx.cell.config
+    cfg, reference = ctx.cell.config, ctx.cell.reference
     key = jax.random.PRNGKey(traffic.key_seed(ctx.seed))
     with jax.default_device(ctx.devices[0]):
         return reference.train_readings(

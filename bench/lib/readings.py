@@ -1,7 +1,9 @@
 """Helpers the per-layer metric readers share.
 
 A driver hands every reader the same readings ``rd``: ``dims`` and
-``precision`` of the configuration, ``peak`` of the device, ``chips``,
+``precision`` of the configuration, ``counts`` (the module that the
+configuration names as its ``reference``, whose count functions take
+``dims``), ``peak`` of the device, ``chips``,
 ``window_s``; for serving ``requests`` (per request: ``prompt_len``,
 ``max_new``, ``due``, ``produce_s``, ``first_token``, ``done``, times on
 the host's ``perf_counter``), ``window`` (engine counters over the
@@ -47,7 +49,7 @@ def serve_mfu(rd: dict) -> float | None:
     d = traced_requests(rd, DECODE)
     if a is None or d is None:
         return None
-    dm, admitted = rd["dims"], a[2]
-    ops = sum(flops.prefill_flops(dm, n) for n, _ in admitted)
-    ops += flops.decode_flops(dm, [c for n, k in admitted for c in flops.decode_contexts(n, k)])
+    dm, counts, admitted = rd["dims"], rd["counts"], a[2]
+    ops = sum(counts.prefill_flops(dm, n) for n, _ in admitted)
+    ops += counts.decode_flops(dm, [c for n, k in admitted for c in flops.decode_contexts(n, k)])
     return 100.0 * ops / ((a[0] + d[0]) * rd["peak"]["bf16_flops_per_s"])

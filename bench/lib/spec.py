@@ -1,6 +1,12 @@
 """Finding a cell's files by the names in ``BENCHMARK.json``.
 
 - configuration: the ``file`` of its entry in ``configs``;
+- its architecture: the two modules the configuration names, by path
+  from the root, under ``program`` (builds the program's model from the
+  file's published keys and ``precision``, names its parameter leaves as
+  the reference does, states the family's small size) and ``reference``
+  (the plain float32 reference, importing nothing of the program, and
+  the operations and bytes its equations count);
 - traffic mix: ``bench/traffic/<traffic>.json``, whose ``kind`` names the
   driver ``bench/kinds/<kind>.py``;
 - per-layer metric: ``bench/metrics/<name>.py``, which defines
@@ -8,21 +14,24 @@
   there is nothing to read;
 - a cell not yet proven on the chip: ``bench/candidates.json``.
 
-Adding a cell, a mix or a metric adds files; none of these is edited.
+Adding a cell, a mix, a metric or an architecture adds files; none of
+these is edited.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import importlib
 import importlib.util
 import json
+import re
 import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable
 
 ROOT = Path(__file__).resolve().parents[2]
-BENCH = ROOT / "bench"
 
 
 def load_spec(root: Path = ROOT) -> dict:
@@ -37,6 +46,9 @@ class Cell:
     mix: dict
     spec: dict
     entry: dict
+    program: ModuleType
+    reference: ModuleType
+    root: Path = ROOT
 
     def end_to_end(self) -> list[dict]:
         return [m for m in self.spec["end_to_end"] if self._has(m)]
@@ -78,8 +90,40 @@ def cell(name: str, root: Path = ROOT) -> Cell:
     entry = entries[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = json.loads((root / configs[entry["config"]]["file"]).read_text())
+    program, reference = family(config, root)
     mix = json.loads((root / "bench" / "traffic" / f"{entry['traffic']}.json").read_text())
-    return Cell(name, int(entry["chips"]), config, mix, spec, entry)
+    return Cell(name, int(entry["chips"]), config, mix, spec, entry, program, reference, root)
+
+
+FAMILY = ("program", "reference")
+
+
+def family(config: dict, root: Path = ROOT) -> tuple[ModuleType, ModuleType]:
+    """The ``program`` and ``reference`` modules that ``config`` names; a
+    configuration without either key is an error, never a default."""
+    for key in FAMILY:
+        if key not in config:
+            raise KeyError(f"configuration {config.get('name')!r} names no {key!r} module")
+    return module(root / config["program"]), module(root / config["reference"])
+
+
+def module(path: Path) -> ModuleType:
+    """The Python file at ``path``, loaded once a process."""
+    path = path.resolve()
+    tag = re.sub(r"\W", "_", path.stem) + "_" + hashlib.sha1(str(path).encode()).hexdigest()[:10]
+    name = "bench._by_path." + tag
+    if name not in sys.modules:
+        if not path.is_file():
+            raise FileNotFoundError(f"no module file {path}")
+        loaded = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(loaded)
+        sys.modules[name] = mod  # a dataclass looks its module up while it is made
+        try:
+            loaded.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 def driver(kind: str):
@@ -87,12 +131,5 @@ def driver(kind: str):
     return importlib.import_module(f"bench.kinds.{kind}")
 
 
-def reader(metric: str) -> Callable[[dict], Any]:
-    path = BENCH / "metrics" / f"{metric}.py"
-    mod_name = "bench.metrics._" + metric.replace(".", "_").replace("-", "_")
-    if mod_name not in sys.modules:
-        loaded = importlib.util.spec_from_file_location(mod_name, path)
-        module = importlib.util.module_from_spec(loaded)
-        loaded.loader.exec_module(module)
-        sys.modules[mod_name] = module
-    return sys.modules[mod_name].read
+def reader(metric: str, root: Path = ROOT) -> Callable[[dict], Any]:
+    return module(root / "bench" / "metrics" / f"{metric}.py").read

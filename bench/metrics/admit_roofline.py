@@ -14,10 +14,10 @@ def read(rd):
     if got is None:
         return None
     secs, _, admitted = got
-    dm, p, peak = rd["dims"], rd["precision"], rd["peak"]
+    dm, c, p, peak = rd["dims"], rd["counts"], rd["precision"], rd["peak"]
     least = sum(
-        flops.least_seconds(flops.prefill_flops(dm, n),
-                            flops.prefill_bytes(dm, n, p["param_dtype"], p["kv_cache_dtype"]),
+        flops.least_seconds(c.prefill_flops(dm, n),
+                            c.prefill_bytes(dm, n, p["param_dtype"], p["kv_cache_dtype"]),
                             peak)
         for n, _ in admitted)
     return 100.0 * least / secs

@@ -22,5 +22,6 @@ def read(rd):
     secs, calls, admitted = got
     dm, p = rd["dims"], rd["precision"]
     contexts = [c for plen, n in admitted for c in flops.decode_contexts(plen, n)]
-    ops, nbytes = flops.decode_totals(dm, calls, contexts, p["param_dtype"], p["kv_cache_dtype"])
+    ops, nbytes = rd["counts"].decode_totals(dm, calls, contexts, p["param_dtype"],
+                                             p["kv_cache_dtype"])
     return 100.0 * flops.least_seconds(ops, nbytes, rd["peak"]) / secs

@@ -1,9 +1,8 @@
 """Model FLOP utilisation of training, in %: operations the forward and
 backward passes need per sequence (the layers on every position, causal
-attention, the unembedding; recomputation not counted) times the steps
+attention, the unembedding; recomputation not counted, as the
+reference's ``train_flops_per_sequence`` counts them) times the steps
 completed in the traced window, over the window at the chip's peak."""
-
-from bench.lib import flops
 
 PROGRAM = r"^jit_step$"
 
@@ -15,5 +14,5 @@ def read(rd):
     _, calls = tr.programs(PROGRAM)
     if not calls:
         return None
-    ops = calls * t["batch"] * flops.train_flops_per_sequence(rd["dims"], t["seq"])
+    ops = calls * t["batch"] * rd["counts"].train_flops_per_sequence(rd["dims"], t["seq"])
     return 100.0 * ops / (tr.window_s * rd["peak"]["bf16_flops_per_s"])

@@ -97,7 +97,7 @@ def assemble(cell, trace: bool, devices, peak, out) -> dict:
         summary = traced.summary()
         rd["trace"] = summary
         for m in cell.per_layer():
-            value = spec.reader(m["name"])(rd)
+            value = spec.reader(m["name"], cell.root)(rd)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         extra_device = {"busy_s": summary.busy_s, "window_s": summary.window_s}

@@ -40,7 +40,6 @@ def main(argv=None) -> None:
     from bench import run as bench_run
     from bench.kinds import open_loop_lm as olm
     from bench.kinds.common import Ctx
-    from bench.lib.reference import Dims
     from bench.lib import spec, traffic
 
     cell = spec.cell(args.workload)
@@ -48,7 +47,7 @@ def main(argv=None) -> None:
     bench_run.enable_cache()
     ctx = Ctx(cell=cell, seed=args.seed, seconds=args.seconds, trace=False,
               devices=devices, out_dir=bench_run.OUT_DIR, started=bench_run.STARTED)
-    dm = Dims.from_config(cell.config)
+    dm = cell.reference.dims(cell.config)
     m, engines, log, group = olm._build(ctx)
     olm._warm(ctx, log, group, dm.vocab)
     base = 0

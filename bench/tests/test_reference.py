@@ -1,6 +1,7 @@
 """The float32 reference against the program at the reduced yi-6b
 configuration on the CPU: the same weights from the same key, and the
-same logits and loss when the program runs in float32.
+same logits and loss when the program runs in float32. The reference is
+the module the yi-6b configurations name.
 
 Departures from the published description are in the reference's
 docstring: adjacent-pair RoPE, random weights, parameters stored in the
@@ -14,12 +15,13 @@ import numpy as np
 import pytest
 
 import repro.configs as configs
-from bench.lib import reference
+from bench.lib import spec
 from repro.models.model import StreamModel
 from repro.models.policy import Policy
 from repro.train import adamw
 from repro.train.trainer import build_train_step
 
+reference = spec.cell("yi6b-l2.train.stream").reference
 CFG = dataclasses.replace(configs.get_reduced("yi-6b"), rope_theta=5e6, norm_eps=1e-5)
 DIMS = reference.Dims(CFG.d_model, CFG.n_layers, CFG.n_heads, CFG.n_kv_heads, CFG.hd,
                       CFG.d_ff, CFG.vocab, CFG.rope_theta, CFG.norm_eps)
